@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import bucketing as BK
-from repro.core import compat
 from repro.core.comm import Comm
 
 # collective primitives, normalized ("psum2" is how psum binds on newer
@@ -111,11 +110,11 @@ class AuditReport:
 # ---------------------------------------------------------------------------
 
 def _abstract_mesh(axes, sizes):
-    try:
-        from jax.sharding import AbstractMesh  # jax >= 0.5
-    except ImportError:
-        from jax._src.mesh import AbstractMesh
-    return AbstractMesh(tuple(zip(axes, sizes)))
+    """Device-free mesh for the ``shard_map`` trace (``jax.make_jaxpr``
+    only — it never reaches the compiler)."""
+    return jax.sharding.AbstractMesh(
+        tuple(sizes), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def worker_axes_sizes(trainer) -> Tuple[Tuple[str, ...], Tuple[int, ...]]:
@@ -258,8 +257,8 @@ def trace_collectives(trainer, *, seq: int = 16,
         mesh_axes.append(a)
         mesh_sizes.append(s)
     mesh = _abstract_mesh(tuple(mesh_axes), tuple(mesh_sizes))
-    f = compat.shard_map(one, in_specs=P(), out_specs=P(),
-                         axis_names=set(axes), mesh=mesh, check=False)
+    f = jax.shard_map(one, in_specs=P(), out_specs=P(),
+                      axis_names=set(axes), mesh=mesh, check_vma=False)
     closed, out_shape = jax.make_jaxpr(f, return_shape=True)(
         params_i, state_i, batch_i)
 

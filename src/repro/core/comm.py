@@ -62,8 +62,7 @@ class Comm:
         return self.axes if len(self.axes) > 1 else self.axes[0]
 
     def size(self) -> int:
-        from repro.core.compat import axis_size
-        return axis_size(self.axes)
+        return jax.lax.axis_size(self.axes)
 
     def index(self):
         return jax.lax.axis_index(self.axes)
@@ -84,7 +83,7 @@ class Comm:
         # Flattened axis tuples: decompose into per-axis gathers, innermost
         # first — concatenation is then outer-major, exactly the flattened-
         # axis order of the native tuple call. (vmap's all_gather batching
-        # rule rejects tuples — the simulation / GSPMD-vmap regime — and the
+        # rule rejects tuples — the simulation regime — and the
         # decomposition is collective-equivalent on a mesh: same payload,
         # one ring per topology level.)
         if not tiled:

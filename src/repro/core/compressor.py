@@ -251,25 +251,16 @@ def pad_mask(layout: LeafLayout, dtype=jnp.float32) -> Optional[jnp.ndarray]:
 def ambient_auto_mesh():
     """(axis->size) for GSPMD-*auto* axes of the ambient mesh, or None.
 
-    Inside a partial-manual shard_map body the abstract mesh reports the
-    manual worker axes as Manual — constraints must only mention Auto axes.
+    The ambient mesh is the one ``jax.set_mesh`` or an enclosing
+    ``shard_map`` binds. Inside a partial-manual shard_map body it reports
+    the manual worker axes as Manual — constraints must only mention Auto
+    axes.
     """
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and am.axis_names:
-            n2t = dict(zip(am.axis_names, am.axis_types))
-            return {a: int(am.shape[a]) for a in am.axis_names
-                    if "Auto" in str(n2t[a])}
-    except Exception:
-        pass
-    try:
-        from jax.interpreters.pxla import thread_resources
-        m = thread_resources.env.physical_mesh
-        if not m.empty:
-            return {a: int(s) for a, s in zip(m.axis_names, m.devices.shape)}
-    except Exception:
-        pass
-    return None
+    am = jax.sharding.get_abstract_mesh()
+    auto = {a: int(am.shape[a])
+            for a, t in zip(am.axis_names, am.axis_types)
+            if t == jax.sharding.AxisType.Auto}
+    return auto or None
 
 
 def constrain(x, entries) -> jnp.ndarray:

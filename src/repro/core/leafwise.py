@@ -50,6 +50,9 @@ def make_plan(param_shapes, specs, dp_mask, n_workers: int,
     if dp_mask is None:
         dp_mask = jax.tree.map(lambda _: True, param_shapes)
     model_axis_sizes = model_axis_sizes or {}
+    # a model axis of size 1 shards nothing: plan the global layouts, so a
+    # data-parallel-only mesh keeps sim mode's state and wire layouts
+    tp_local = any(s > 1 for s in model_axis_sizes.values())
     hierarchy = norm_hierarchy(hierarchy, n_workers)
     leaves, treedef = jax.tree.flatten(param_shapes)
     specs_f = treedef.flatten_up_to(specs)
@@ -57,7 +60,7 @@ def make_plan(param_shapes, specs, dp_mask, n_workers: int,
     layouts = [
         C.make_layout(l.shape, s, n_workers,
                       rest_factor=C.spec_model_factor(s, model_axis_sizes),
-                      force_flatten=bool(model_axis_sizes),
+                      force_flatten=tp_local,
                       n_inner=hierarchy.inner if hierarchy else 1)
         for l, s in zip(leaves, specs_f)]
     vspecs = [C.view_spec_entries(lo, sp)

@@ -179,7 +179,8 @@ def onebit_allreduce_view(comm: Comm, z_view: jnp.ndarray, ef: EFState,
     """
     if cfg.hierarchy is not None:
         assert layout.n_inner == cfg.hierarchy.inner, (layout, cfg.hierarchy)
-        return _hier_allreduce_view(comm, z_view, ef, layout, cfg, vspec)
+        return _hier_allreduce_view(comm, z_view, ef, layout, cfg, vspec,
+                                    worker_index)
     codec = cfg.codec
     cst = lambda x: C.constrain(x, vspec)
 
@@ -206,7 +207,8 @@ def onebit_allreduce_view(comm: Comm, z_view: jnp.ndarray, ef: EFState,
 
 
 def _hier_reduce_scatter(inner, z_view, layout, cfg, vspec):
-    """Hier step 1: intra-pod reduce-scatter. Returns ``(own slice, j)``."""
+    """Hier step 1: intra-pod reduce-scatter. Returns this worker's own
+    slice (inner index j)."""
     ni, no = layout.n_inner, layout.n_outer
     vs = layout.view_shape
     cst = lambda x: C.constrain(x, vspec)
@@ -215,11 +217,9 @@ def _hier_reduce_scatter(inner, z_view, layout, cfg, vspec):
         recv = inner.all_to_all(zr.astype(cfg.comm_dtype),
                                 split_axis=0, concat_axis=0)
         own = recv.astype(jnp.float32).mean(axis=0)        # (no, A/n, *rest)
-        j = inner.index()
     else:
         own = zr[0]
-        j = jnp.zeros((), jnp.int32)
-    return cst(own.astype(cfg.compute_dtype)), j
+    return cst(own.astype(cfg.compute_dtype))
 
 
 def _hier_worker_encode(own, ef: EFState, layout, cfg, vspec, j):
@@ -274,7 +274,7 @@ def _hier_gather_out(inner, out_slice, layout, cfg, vspec):
 
 def _hier_allreduce_view(comm: Comm, z_view: jnp.ndarray, ef: EFState,
                          layout: C.LeafLayout, cfg: OneBitConfig,
-                         vspec=None):
+                         vspec=None, worker_index=None):
     """Topology-aware two-level AllReduce (intra-pod × inter-pod).
 
     Schedule, per worker (inner index j, outer index k):
@@ -301,15 +301,20 @@ def _hier_allreduce_view(comm: Comm, z_view: jnp.ndarray, ef: EFState,
     no = layout.n_outer
     cst = lambda x: C.constrain(x, vspec)
     outer, inner = comm.split(h.outer_axes, h.inner_axes)
+    # (j, k) from the flat outer-major worker index: jax 0.9 cannot lower
+    # axis_index of a sub-group of the worker axes inside the optimizer's
+    # nested 'model' shard_map, so the caller's index is split here
+    w = comm.index() if worker_index is None else worker_index
+    j, k = w % layout.n_inner, w // layout.n_inner
 
-    own, j = _hier_reduce_scatter(inner, z_view, layout, cfg, vspec)
+    own = _hier_reduce_scatter(inner, z_view, layout, cfg, vspec)
     payload, err_w, mask_full, use_k = _hier_worker_encode(
         own, ef, layout, cfg, vspec, j)
 
     # --- 2b: inter-pod scatter: pod k collects sub-chunk k -------------------
     recv = _map_a2a(outer, payload, vspec)
 
-    widx = j * no + outer.index()
+    widx = j * no + k
     payload_s, err_s = _hier_server_encode(recv, ef, layout, cfg, vspec,
                                            mask_full, use_k, widx)
 

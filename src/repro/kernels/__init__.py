@@ -3,9 +3,9 @@ fused error-feedback 1-bit compress/decompress + fused 0/1 Adam local step.
 
 ``ops`` exposes the jitted 2-D kernel wrappers, ``ref`` their pure-jnp
 oracles, and ``dispatch`` the comm-view-level glue that
-``OptimizerConfig.use_pallas=True`` routes through. Validated with
-interpret=True against ref.py on CPU; on TPU the same calls compile to
-fused Mosaic kernels.
+``OptimizerConfig.use_pallas=True`` routes through. Validated in
+interpret mode against ref.py on CPU; on TPU the same calls compile to
+fused Mosaic kernels (``ops`` picks the mode from the backend).
 """
 from repro.kernels import dispatch, ops, ref
 

@@ -22,12 +22,10 @@ The combine step also psums over manual tensor-parallel axes and applies
 Partitioning rules: views that are model-sharded over a GSPMD-*auto* mesh
 axis no longer fall back to jnp — :func:`shard_context` derives the
 per-shard local layout of a structured view and each view function wraps
-its kernels in a manual ``shard_map`` over the view's model axes (fully
-manual over every mesh axis on jax 0.4.x, whose partitioner rejects
-Pallas calls inside partial-manual regions), recursing into itself with
-the local layout and the model axes extended — so scales still psum to
-their global values and the outputs come back sharded exactly as the
-inputs were. ``kernel_safe`` is the dispatch gate: manual-TP vspecs are
+its kernels in a manual ``shard_map`` over the view's model axes,
+recursing into itself with the local layout and the model axes extended —
+so scales still psum to their global values and the outputs come back
+sharded exactly as the inputs were. ``kernel_safe`` is the dispatch gate: manual-TP vspecs are
 handled by the psum machinery, auto-mesh vspecs require a valid
 ``shard_context``, and a named vspec on a *meshless* trace is only safe
 when the view is the global buffer (``rest_factor == 1``).
@@ -46,11 +44,19 @@ from repro.core import compressor as C
 from repro.kernels import ops
 
 
-def _largest_divisor(x: int, cap: int) -> int:
-    d = min(x, cap)
-    while x % d:
-        d -= 1
-    return d
+def _tile(x: int, cap: int, quantum: int) -> int:
+    """Kernel block extent along a frame dim of size ``x``: the largest
+    divisor of ``x`` that is <= ``cap`` and a multiple of the TPU tiling
+    ``quantum`` (8 sublanes for rows, 128 lanes for cols), else the whole
+    dim — the two block extents the TPU lowering accepts."""
+    for d in range(min(x, cap) // quantum * quantum, 0, -quantum):
+        if x % d == 0:
+            return d
+    return x
+
+
+def _block_rows(rows: int) -> int:
+    return _tile(rows, 8, 8)
 
 
 @functools.lru_cache(maxsize=None)
@@ -203,37 +209,12 @@ def shard_context(layout: C.LeafLayout, vspec):
                         entries=entries)
 
 
-def _ambient_concrete_mesh():
-    try:
-        from jax.interpreters.pxla import thread_resources
-        m = thread_resources.env.physical_mesh
-        if not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
-
-
 def _shard_wrap(fn, in_specs, out_specs, ctx: ShardContext):
     """Manual ``shard_map`` around one kernel dispatch — the partitioning
-    rule. On current jax the view's model axes alone go manual (the mesh is
-    picked up ambiently); the jax 0.4.x partitioner rejects Pallas calls
-    inside partial-manual regions (``IsManualSubgroup`` check), so there
-    every mesh axis goes manual — unmentioned axes are a replicated claim,
-    which holds for the optimizer's comm buffers under a pure-GSPMD trace.
-    """
-    from repro.core import compat
-    if hasattr(jax, "shard_map"):
-        return compat.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
-                                axis_names=ctx.names)
-    mesh = _ambient_concrete_mesh()
-    if mesh is None:
-        raise RuntimeError(
-            f"shard_context engaged for axes {ctx.names} but no concrete "
-            f"mesh is ambient; on jax<0.5 the sharded kernel dispatch "
-            f"needs the `with mesh:` context it was traced under")
-    return compat.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
-                            axis_names=tuple(mesh.axis_names), mesh=mesh)
+    rule. The view's model axes alone go manual; the mesh is the ambient
+    one the enclosing trace bound."""
+    return jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=set(ctx.names), check_vma=False)
 
 
 # Static VMEM budget per core for the pre-check: the hardware holds ~16
@@ -268,7 +249,7 @@ def frame_precheck(layout: C.LeafLayout, *, block_rows: int = 8,
         issues.append(
             f"frame cols={cols} exceeds FRAME_MAX_COLS={C.FRAME_MAX_COLS} "
             f"— view_rows_cols should have folded this view")
-    br = _largest_divisor(rows, block_rows) if rows else 0
+    br = _tile(rows, block_rows, 8) if rows else 0
     est = _KERNEL_OPERANDS * br * cols * 4
     if est > vmem_budget:
         issues.append(
@@ -371,7 +352,7 @@ def ef_compress_view(z, err, layout: C.LeafLayout, mode: C.ScaleMode,
         cnts = jnp.take(jnp.asarray(_slice_counts_np(layout)), inner_index,
                         axis=0)
     z2, e2 = z.reshape(rows, cols), err.reshape(rows, cols)
-    br = _largest_divisor(rows, 8)
+    br = _block_rows(rows)
     if eff == "row" and ndim == 3 and not model_axes and \
             layout.rest_factor == 1:
         # per-2-D-row scales: the single-pass fully fused kernel applies
@@ -417,7 +398,7 @@ def server_compress_view(avg, err, layout: C.LeafLayout, mode: C.ScaleMode,
     cnts = jnp.take(jnp.asarray(_chunk_counts_np(layout)), worker_index,
                     axis=0)
     z2, e2 = avg.reshape(rows, cols), err.reshape(rows, cols)
-    br = _largest_divisor(rows, 8)
+    br = _block_rows(rows)
     rowsum = ops.abs_rowsum(z2, e2, cnts, block_rows=br)
     rf = layout.rest_factor
     if mode == "row":
@@ -459,7 +440,7 @@ def decompress_view(packed, scales, layout: C.LeafLayout,
             // int(np.prod(layout.view_shape[:-1])))
     p2 = packed.reshape(rows, cols // 8)
     srow = _scales_to_rows(scales, packed.shape[:-1], rows, layout)
-    out2 = ops.decompress(p2, srow, block_rows=_largest_divisor(rows, 8),
+    out2 = ops.decompress(p2, srow, block_rows=_block_rows(rows),
                           dtype=dtype)
     return out2.reshape(packed.shape[:-1] + (layout.pack_count,))
 
@@ -498,7 +479,7 @@ def fused_local_step_view(g, m, u, v, lr, beta1, eps,
     rows, cols = C.view_rows_cols(layout)
     vs = layout.view_shape
     r2 = lambda a: a.reshape(rows, cols)
-    block = (_largest_divisor(rows, 8), _largest_divisor(cols, 1024))
+    block = (_block_rows(rows), _tile(cols, 1024, 128))
     if kind == "sgd":
         mh2, uh2, d2 = ops.fused_local_step_sgd(r2(g), r2(m), r2(u), lr,
                                                 beta1, block=block)

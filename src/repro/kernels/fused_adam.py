@@ -26,6 +26,8 @@ results compose to the global update by construction.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -53,7 +55,7 @@ def _fused_kernel(g_ref, m_ref, u_ref, v_ref, lr_ref, b1_ref, omb1_ref,
 
 
 def fused_local_step(g, m, u, v, lr, beta1, *, eps=1e-8,
-                     block=(8, 1024), interpret: bool = True):
+                     block=(8, 1024), interpret: bool):
     """One fused 0/1 Adam local step over (R, C) views.
 
     Returns (m', u', delta). ``lr`` traced scalar; β₁ static-ish scalar.
@@ -67,7 +69,6 @@ def fused_local_step(g, m, u, v, lr, beta1, *, eps=1e-8,
     omb1_arr = jnp.asarray(1.0 - beta1, jnp.float32).reshape(1, 1)
     tile = lambda: pl.BlockSpec((br, bc), lambda i, j: (i, j))
     scal = lambda: pl.BlockSpec((1, 1), lambda i, j: (0, 0))
-    import functools
     return pl.pallas_call(
         functools.partial(_fused_kernel, eps=eps),
         grid=grid,
@@ -98,7 +99,7 @@ def _fused_kernel_sgd(g_ref, m_ref, u_ref, lr_ref, b1_ref, omb1_ref,
 
 
 def fused_local_step_sgd(g, m, u, lr, beta1, *, block=(8, 1024),
-                         interpret: bool = True):
+                         interpret: bool):
     """One fused momentum-SGD local step over (R, C) views.
 
     Returns (m', u', delta) with delta = lr·m' — the no-variance analogue of

@@ -1,8 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (the kernels execute via the Pallas
-interpreter for correctness validation); on TPU the same calls compile to
-fused Mosaic kernels.
+This is the one place that decides ``interpret``: off-TPU the kernels
+execute via the Pallas interpreter (correctness validation on CPU); on TPU
+the same calls compile to fused Mosaic kernels.
 
 All wrappers take 2-D (rows, cols) operands; ``counts`` is the optional
 per-row true-element count for pad-exact scales/error-feedback (None means

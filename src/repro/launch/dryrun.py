@@ -32,7 +32,7 @@ from repro.configs import ALL_SHAPES, ASSIGNED, get
 from repro.core import (CODEC_NAMES, OptimizerConfig, REGISTRY_NAMES,
                         schedules as S)
 from repro.launch import shapes as SH
-from repro.launch.mesh import make_production_mesh, worker_axes
+from repro.launch.mesh import make_mesh, make_production_mesh, worker_axes
 from repro.serve import Server
 from repro.train import Trainer, TrainerConfig
 
@@ -324,7 +324,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
                 "note": spec.skip_notes}
     if mesh_shape is not None:  # perf-iteration override (same chip count)
         dp, tp = mesh_shape
-        from repro.core.compat import make_mesh
         mesh = make_mesh((dp, tp), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)

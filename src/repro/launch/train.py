@@ -1,10 +1,10 @@
 """Training driver CLI.
 
-Single-host modes (this container): ``--mode single`` (one worker) or
-``--mode sim --workers N`` (N simulated paper-workers via vmap — the real
-0/1 Adam communication semantics at algorithm level). On a TPU fleet the
-same Trainer builds the mesh-mode step (``--mode mesh``) where workers are
-data-parallel groups of the production mesh.
+Modes: ``--mode single`` (one worker), ``--mode sim --workers N`` (N
+simulated paper-workers via vmap on one device — the real 0/1 Adam
+communication semantics at algorithm level), and ``--mode mesh`` (one
+paper-worker per device present: a ``data = jax.device_count()``,
+``model = 1`` mesh, the exchange over the chips' interconnect).
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch gpt2 --smoke \\
@@ -25,6 +25,7 @@ from repro.configs import get
 from repro.core import (CODEC_NAMES, Hierarchy, OptimizerConfig,
                         REGISTRY_NAMES, comm_accounting, schedules as S)
 from repro.data import DataConfig, SyntheticLM
+from repro.launch.cache import enable_compile_cache
 from repro.train import Trainer, TrainerConfig
 
 
@@ -94,7 +95,7 @@ def _run_elastic(args, cfg, opt_cfg, acct):
         print(f"saved checkpoint to {args.save} (width {n_final})")
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -103,7 +104,9 @@ def main():
                     choices=list(REGISTRY_NAMES))
     ap.add_argument("--mode", default="single",
                     choices=["single", "sim", "mesh"])
-    ap.add_argument("--workers", type=int, default=4)
+    ap.add_argument("--workers", type=int, default=4,
+                    help="simulated workers (sim mode); mesh mode uses "
+                         "one worker per device")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -148,29 +151,37 @@ def main():
                          "through repro.elastic.FleetSim — EF state and "
                          "anchors are resharded, not reset; the resize is "
                          "recorded in the run summary")
-    ap.add_argument("--multi-pod", action="store_true")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def run(args, opt_cfg=None, on_step=None):
+    """Train as the CLI does. Returns the per-step record — ``loss``,
+    ``synced``, ``var_round`` and ``step_s`` (wall seconds of each step,
+    the first one including compilation) — with the final ``params``,
+    ``state`` and the ``trainer``. ``opt_cfg`` overrides the optimizer
+    config ``args`` would build; ``on_step(step, params, state)``, if
+    given, is called after each step."""
     spec = get(args.arch)
     cfg = spec.smoke if args.smoke else spec.config
-    opt_cfg = build_opt_cfg(args)
+    opt_cfg = opt_cfg or build_opt_cfg(args)
+    key = jax.random.PRNGKey(args.seed)
+    tcfg = dict(micro_batches=args.micro_batches)
 
     if args.mode == "mesh":
-        from repro.launch.mesh import make_production_mesh, worker_axes
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+        from repro.launch.mesh import make_local_mesh, worker_axes
+        mesh = make_local_mesh()
         tr = Trainer(cfg, opt_cfg, mesh=mesh, trainer_cfg=TrainerConfig(
-            micro_batches=args.micro_batches, worker_axes=worker_axes(mesh)))
-        raise SystemExit("mesh mode requires a real TPU fleet; use "
-                         "launch/dryrun.py for the compile-only proof")
-
-    n = args.workers if args.mode == "sim" else 1
-    tr = Trainer(cfg, opt_cfg, n_workers=n, trainer_cfg=TrainerConfig(
-        micro_batches=args.micro_batches))
+            worker_axes=worker_axes(mesh), **tcfg))
+    else:
+        tr = Trainer(cfg, opt_cfg,
+                     n_workers=args.workers if args.mode == "sim" else 1,
+                     trainer_cfg=TrainerConfig(**tcfg))
+    n = tr.n_workers
     acct = comm_accounting(tr.opt)
     print(f"arch={cfg.name} params(dp)={acct['dp_params']/1e6:.2f}M "
           f"codec={acct['codec']} "
           f"bits/param/sync={acct['bits_per_param_sync']:.3f} "
-          f"workers={n} optimizer={args.optimizer}")
+          f"workers={n} mode={args.mode} optimizer={args.optimizer}")
     if args.bucket_mb:
         print(f"bucketed exchange: {int(acct['exchange_units'])} buckets "
               f"({args.bucket_mb}MiB budget) over "
@@ -188,16 +199,23 @@ def main():
                              "resharding path runs over the sim trainer)")
         return _run_elastic(args, cfg, opt_cfg, acct)
 
-    if args.mode == "sim":
-        params, state = tr.sim_init(jax.random.PRNGKey(args.seed))
+    if args.mode == "mesh":
+        params, state = tr.mesh_init(key)
+        step_fn, _ = tr.mesh_step_fn()
+    elif args.mode == "sim":
+        params, state = tr.sim_init(key)
         step_fn = tr.sim_step_fn()
     else:
-        params, state = tr.single_init(jax.random.PRNGKey(args.seed))
+        params, state = tr.single_init(key)
         step_fn = tr.single_step_fn()
 
+    # bidirectional encoders train masked-LM, as BERT does
     data = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=args.seq,
-                                  global_batch=args.batch, seed=args.seed))
+                                  global_batch=args.batch, seed=args.seed,
+                                  kind="lm" if cfg.causal else "mlm"))
 
+    first = lambda x: np.asarray(x).reshape(-1)[0]
+    rec = {"loss": [], "synced": [], "var_round": [], "step_s": []}
     t0 = time.time()
     comp_bytes = 0.0
     rounds = 0
@@ -209,11 +227,18 @@ def main():
         if cfg.vision_tokens:
             batch["vision_embeds"] = jnp.zeros(
                 (args.batch, cfg.vision_tokens, cfg.d_model))
-        if not cfg.causal:
-            batch["loss_mask"] = jnp.ones((args.batch, args.seq))
+        ts = time.perf_counter()
         params, state, met = step_fn(params, state, batch)
-        synced = bool(np.asarray(met["synced"]).reshape(-1)[0])
-        var_r = bool(np.asarray(met["var_round"]).reshape(-1)[0])
+        jax.block_until_ready((params, state, met))
+        rec["step_s"].append(time.perf_counter() - ts)
+        synced = bool(first(met["synced"]))
+        var_r = bool(first(met["var_round"]))
+        loss = float(first(met["loss"]))
+        rec["loss"].append(loss)
+        rec["synced"].append(synced)
+        rec["var_round"].append(var_r)
+        if on_step is not None:
+            on_step(step, params, state)
         if synced:
             comp_bytes += acct["compressed_bytes_per_sync"]
             rounds += 1
@@ -221,9 +246,8 @@ def main():
             comp_bytes += acct["fullprec_bytes_per_round"]
             rounds += 1
         if step % args.log_every == 0 or step == args.steps - 1:
-            loss = float(np.asarray(met["loss"]).reshape(-1)[0])
             print(f"step {step:5d} loss {loss:.4f} "
-                  f"lr {float(np.asarray(met['lr']).reshape(-1)[0]):.2e} "
+                  f"lr {float(first(met['lr'])):.2e} "
                   f"sync={synced} var={var_r} "
                   f"[{time.time()-t0:.1f}s]")
 
@@ -236,6 +260,13 @@ def main():
                      step=args.steps,
                      meta={"arch": cfg.name, "n_workers": n})
         print(f"saved checkpoint to {args.save}")
+    rec.update(params=params, state=state, trainer=tr)
+    return rec
+
+
+def main(argv=None):
+    enable_compile_cache()
+    run(parse_args(argv))
 
 
 if __name__ == "__main__":

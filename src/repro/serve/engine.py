@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import compat
 from repro.core.comm import NullComm, mesh_comm
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
@@ -166,10 +165,9 @@ class Server:
             self.template, is_leaf=is_pd)
         ci = jax.tree.map(lambda _: P(None, W), self.abstract_cache())
         bi = P(W)
-        shm = compat.shard_map(body, mesh=self.mesh,
-                                in_specs=(pi, bi, ci),
-                                out_specs=(P(W), ci),
-                                axis_names=set(W))
+        shm = jax.shard_map(body, mesh=self.mesh, in_specs=(pi, bi, ci),
+                            out_specs=(P(W), ci), axis_names=set(W),
+                            check_vma=False)
         ps = self.param_shardings()
         cs = self.cache_shardings()
         bs = self._batch_sharding(prefill=True)
@@ -206,10 +204,10 @@ class Server:
                         else P()),
             self.template, is_leaf=is_pd)
         ci = jax.tree.map(lambda _: P(None, W), self.abstract_cache())
-        shm = compat.shard_map(body, mesh=self.mesh,
-                                in_specs=(pi, ci, P(W), P()),
-                                out_specs=(P(W), ci),
-                                axis_names=set(W))
+        shm = jax.shard_map(body, mesh=self.mesh,
+                            in_specs=(pi, ci, P(W), P()),
+                            out_specs=(P(W), ci), axis_names=set(W),
+                            check_vma=False)
         ps = self.param_shardings()
         cs = self.cache_shardings()
         return jax.jit(shm, in_shardings=(ps, cs, None, None),

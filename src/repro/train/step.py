@@ -5,10 +5,11 @@ Three execution modes share one per-worker step function:
   * ``mesh``   — partial-manual ``jax.shard_map``: manual over the worker
     axes (the paper's communication pattern, hand-written collectives),
     GSPMD-auto over 'model' (tensor parallelism via sharding constraints).
-    This is the production / dry-run path.
+    This is the multi-chip path (``launch/train.py --mode mesh``) and the
+    dry-run's.
   * ``sim``    — ``jax.vmap(axis_name=...)`` materializes n workers on one
     device; identical collectives run through the vmap axis. Used by the
-    convergence tests/benchmarks (paper Fig. 2) on CPU.
+    convergence tests/benchmarks (paper Fig. 2) and the one-chip run.
   * ``single`` — one worker, NullComm. CPU smoke tests.
 
 Parameters/optimizer state carry a leading worker axis for DP-replicated
@@ -25,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import api as opt_api
-from repro.core import compat
 from repro.core.comm import (Comm, NullComm, mesh_comm, norm_hierarchy,
                              sim_comm)
 from repro.models import transformer as T
@@ -174,14 +174,8 @@ class Trainer:
         # The optimizer runs in the FULLY-manual domain: manual over the
         # worker axes (outer shard_map) AND over 'model' (nested shard_map in
         # _per_worker_step) — every op is chip-local except the worker-axis
-        # collectives, so GSPMD never re-gathers the comm views. jax 0.4.x
-        # cannot nest a manual region inside a partial-auto one (the XLA
-        # partitioner of that vintage rejects manual-subgroup resharding),
-        # so there the optimizer stays in the GSPMD-auto domain: structured
-        # per-leaf layouts chunk along a replicated axis and the views keep
-        # their model sharding via compressor.constrain.
-        if (mesh is not None and "model" in mesh.axis_names
-                and hasattr(jax, "shard_map")):
+        # collectives, so GSPMD never re-gathers the comm views.
+        if mesh is not None and "model" in mesh.axis_names:
             self.model_axes = ("model",)
             self.model_sizes = {"model": mesh.shape["model"]}
         elif mesh is None and trainer_cfg.model_shards > 1:
@@ -235,20 +229,6 @@ class Trainer:
     def _residual_axes(self):
         names = getattr(self, "_worker_axis_names", self.tc.worker_axes)
         return tuple(a for a in names if a not in self.ep_axes)
-
-    def _abstract_tp_mesh(self):
-        """Worker axes + model axes as an abstract mesh — the meshless-TP
-        stand-in for ``self.mesh`` in the nested optimizer shard_map."""
-        if self.hierarchy is not None:
-            axes = list(self.hierarchy.axes)
-            sizes = [self.n_workers // self.hierarchy.inner,
-                     self.hierarchy.inner]
-        else:
-            axes, sizes = ["workers"], [self.n_workers]
-        for a, s in self.model_sizes.items():
-            axes.append(a)
-            sizes.append(s)
-        return compat.abstract_mesh(axes, sizes)
 
     def _local_abstract(self):
         n = self.ep_degree
@@ -347,15 +327,13 @@ class Trainer:
             pm = jax.tree.unflatten(self.treedef,
                                     self.tree_specs.params_model())
             sm = self.tree_specs.state_model_specs()
-            # meshless sim-TP substitutes the abstract mesh ir_audit traces
-            # under — shapes and collectives are identical to the physical
-            # nesting, and the trace never reaches the compiler
-            opt_apply = compat.shard_map(
+            # nested inside the worker-axes shard_map, so the mesh is the
+            # ambient one: the physical mesh, or the abstract mesh that
+            # ir_audit traces meshless TP under
+            opt_apply = jax.shard_map(
                 opt_apply, in_specs=(pm, pm, sm, P()),
                 out_specs=(pm, sm, P()),
-                axis_names=set(self.model_axes),
-                mesh=(self.mesh if self.mesh is not None
-                      else self._abstract_tp_mesh()))
+                axis_names=set(self.model_axes), check_vma=False)
 
         new_p, new_opt, met = opt_apply(p, grads, opt_state, widx)
         met["loss"] = comm.pmean(loss)
@@ -392,23 +370,12 @@ class Trainer:
     # mesh (production) mode
     # ------------------------------------------------------------------ #
     def mesh_step_fn(self):
-        """jit(shard_map(step)) for the production mesh, plus shardings.
-
-        jax 0.4.x cannot run worker-axis collectives inside a partial-auto
-        shard_map region (the XLA partitioner of that vintage rejects
-        manual-subgroup resharding of shape-changing collectives), so the
-        same program is lowered through GSPMD + vmap-over-workers instead:
-        identical per-worker semantics, the worker axes sharded over the
-        real mesh, collectives emitted by the partitioner.
-        """
+        """jit(shard_map(step)) for the mesh, plus shardings."""
         assert self.mesh is not None
-        if not hasattr(jax, "shard_map"):
-            return self._gspmd_mesh_step_fn()
         W = self.tc.worker_axes
         comm = mesh_comm(W)
-        pf = self._params_full_specs_tree()
         pi = self._params_inner_specs_tree()
-        sf, si = self.tree_specs.state_specs()
+        _, si = self.tree_specs.state_specs()
         batch_i = P(W)
         batch_f = P(W)
 
@@ -418,16 +385,13 @@ class Trainer:
                 comm, params, opt_local, batch)
             return new_p, self._unsqueeze_state(new_s, si), met
 
-        shmapped = compat.shard_map(
+        shmapped = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(pi, si, batch_i),
             out_specs=(pi, si, P()),
-            axis_names=set(W))
+            axis_names=set(W), check_vma=False)
 
-        shardings = {
-            "params": self.tree_specs.shardings(self.mesh, pf),
-            "state": self.tree_specs.shardings(self.mesh, sf),
-        }
+        shardings = self._mesh_shardings()
         donate = (0, 1) if self.tc.donate else ()
         fn = jax.jit(
             shmapped,
@@ -437,118 +401,52 @@ class Trainer:
             donate_argnums=donate)
         return fn, shardings
 
-    # ------------------------------------------------------------------ #
-    # GSPMD-vmap fallback (jax 0.4.x mesh mode)
-    # ------------------------------------------------------------------ #
-    def _gspmd_mesh_step_fn(self):
-        """Mesh-mode step as jit(nested-vmap) with worker axes GSPMD-sharded.
-
-        Each mesh worker axis becomes a vmap axis of the same name, so the
-        per-worker step — collectives, hierarchy split and all — is the
-        exact sim-mode trace; ``in_shardings`` lay the mapped axes over the
-        real mesh and GSPMD partitions the lot. Worker-stacked leaves are
-        reshaped (n, ...) -> mesh axis sizes around the vmap; EP leaves
-        split their expert axis over the EP suffix and broadcast over the
-        residual worker axes (the same replication the shard_map specs
-        declare).
-        """
-        W = self.tc.worker_axes
-        sizes = tuple(self.mesh.shape[a] for a in W)
-        ep_deg, n = self.ep_degree, self.n_workers
-        res_ndim = len(W) - len(self.ep_axes)
-        res_sizes, ep_sizes = sizes[:res_ndim], sizes[res_ndim:]
-        comm = mesh_comm(W)
-        one = self._one_worker_fn(comm)
-        mapped = one
-        for name in reversed(W):
-            mapped = jax.vmap(mapped, axis_name=name)
-
-        def split_lead(x):
-            return x.reshape(sizes + x.shape[1:])
-
-        def merge_lead(x):
-            return x.reshape((n,) + x.shape[len(sizes):])
-
-        def split_ep(x, ax):
-            shp = x.shape
-            x = x.reshape(shp[:ax] + ep_sizes + (shp[ax] // ep_deg,)
-                          + shp[ax + 1:])
-            x = jnp.moveaxis(x, tuple(range(ax, ax + len(ep_sizes))),
-                             tuple(range(len(ep_sizes))))
-            return jnp.broadcast_to(x[(None,) * res_ndim],
-                                    res_sizes + x.shape)
-
-        def merge_ep(x, ax):
-            x = x[(0,) * res_ndim]
-            x = jnp.moveaxis(x, tuple(range(len(ep_sizes))),
-                             tuple(range(ax, ax + len(ep_sizes))))
-            shp = x.shape
-            return x.reshape(shp[:ax] + (-1,)
-                             + shp[ax + len(ep_sizes) + 1:])
-
-        def _ep_axis_of(spec):
-            for ax, e in enumerate(tuple(spec)):
-                if e is None:
-                    continue
-                names = e if isinstance(e, tuple) else (e,)
-                if set(names) & set(self.ep_axes):
-                    return ax
-            return None
-
-        def split_state(x, s):
-            if x is None:
-                return None
-            if self._is_per_worker_spec(s):
-                return split_lead(x)
-            ax = _ep_axis_of(s)
-            if ax is not None:
-                return split_ep(x, ax)
-            return jnp.broadcast_to(x[(None,) * len(sizes)],
-                                    sizes + x.shape)
-
-        def merge_state(x, s):
-            if x is None:
-                return None
-            if self._is_per_worker_spec(s):
-                return merge_lead(x)
-            ax = _ep_axis_of(s)
-            if ax is not None:
-                return merge_ep(x, ax)
-            return x[(0,) * len(sizes)]
-
-        sf, si = self.tree_specs.state_specs()
-        pf = self._params_full_specs_tree()
-        pd_flat = self.pd_leaves
-
-        def body(params, opt_state, batch):
-            pl = self.treedef.flatten_up_to(params)
-            pl = [split_lead(x) if pd.dp else split_ep(x, pd.ep_axis or 0)
-                  for x, pd in zip(pl, pd_flat)]
-            p2 = jax.tree.unflatten(self.treedef, pl)
-            s2 = jax.tree.map(split_state, opt_state, si)
-            b2 = jax.tree.map(
-                lambda x: x.reshape(sizes + (x.shape[0] // n,)
-                                    + x.shape[1:]), batch)
-            new_p, new_s, met = mapped(p2, s2, b2)
-            npl = self.treedef.flatten_up_to(new_p)
-            npl = [merge_lead(x) if pd.dp else merge_ep(x, pd.ep_axis or 0)
-                   for x, pd in zip(npl, pd_flat)]
-            return (jax.tree.unflatten(self.treedef, npl),
-                    jax.tree.map(merge_state, new_s, si),
-                    jax.tree.map(lambda x: x[(0,) * len(sizes)], met))
-
-        shardings = {
-            "params": self.tree_specs.shardings(self.mesh, pf),
+    def _mesh_shardings(self):
+        sf, _ = self.tree_specs.state_specs()
+        return {
+            "params": self.tree_specs.shardings(
+                self.mesh, self._params_full_specs_tree()),
             "state": self.tree_specs.shardings(self.mesh, sf),
         }
-        donate = (0, 1) if self.tc.donate else ()
-        fn = jax.jit(
-            body,
-            in_shardings=(shardings["params"], shardings["state"],
-                          NamedSharding(self.mesh, P(W))),
-            out_shardings=(shardings["params"], shardings["state"], None),
-            donate_argnums=donate)
-        return fn, shardings
+
+    def mesh_init(self, key):
+        """(params, opt_state) laid out and placed for ``mesh_step_fn``:
+        the same values ``sim_init`` makes, each worker's state built on
+        its own devices from its local parameters."""
+        assert self.mesh is not None
+        W = self.tc.worker_axes
+        n = self.n_workers
+        pi = self._params_inner_specs_tree()
+        _, si = self.tree_specs.state_specs()
+        init = self.opt.init
+        if self.model_axes:
+            pm = jax.tree.unflatten(self.treedef,
+                                    self.tree_specs.params_model())
+            init = jax.shard_map(
+                init, in_specs=(pm,),
+                out_specs=self.tree_specs.state_model_specs(),
+                axis_names=set(self.model_axes), check_vma=False)
+
+        def body(params):
+            return self._unsqueeze_state(init(self._squeeze(params)), si)
+
+        def make(params):
+            pl = self.treedef.flatten_up_to(params)
+            pl = [jnp.broadcast_to(x[None], (n,) + x.shape) if pd.dp else x
+                  for x, pd in zip(pl, self.pd_leaves)]
+            params = jax.tree.unflatten(self.treedef, pl)
+            state = jax.shard_map(body, mesh=self.mesh, in_specs=(pi,),
+                                  out_specs=si, axis_names=set(W),
+                                  check_vma=False)(params)
+            return params, state
+
+        sh = self._mesh_shardings()
+        # drawn outside the jit, as sim_init does: XLA fuses the jitted
+        # normal sampler differently and the draws would differ by an ulp
+        params = init_params(self.template, key,
+                             dtype=self.model_cfg.param_dtype)
+        return jax.jit(make, out_shardings=(sh["params"], sh["state"]))(
+            params)
 
     def _params_full_specs_tree(self):
         return jax.tree.unflatten(self.treedef,
@@ -681,8 +579,8 @@ class Trainer:
         return jax.tree.map(lambda x: x[i], params)
 
     def _one_worker_fn(self, comm):
-        """Per-worker step on worker-local trees (shared by sim's vmap, the
-        hierarchical nested vmap, and the GSPMD-vmap mesh fallback)."""
+        """Per-worker step on worker-local trees (shared by sim's vmap and
+        the hierarchical nested vmap)."""
 
         def one(params_i, state_i, batch_i):
             # params_i: DP leaves (shape local), EP leaves local slice
@@ -715,7 +613,6 @@ class Trainer:
         for name in reversed(axes):
             mapped = jax.vmap(mapped, axis_name=name)
 
-        @jax.jit
         def fn(params, state, batch):
             # batch: (GB, S) -> per-worker (*sizes, GB/n, S); the stacked
             # params/state keep their flat leading worker axis externally
@@ -740,4 +637,4 @@ class Trainer:
                     jax.tree.map(unlead, new_s),
                     jax.tree.map(unlead, met))
 
-        return fn
+        return jax.jit(fn, donate_argnums=(0, 1) if self.tc.donate else ())

@@ -4,6 +4,7 @@ Covers: train step (shard_map, compressed optimizer), serve decode, and a
 multi-pod (3-axis) variant — the same machinery launch/dryrun.py runs at
 (2,16,16) scale.
 """
+import os
 import subprocess
 import sys
 import textwrap
@@ -134,8 +135,7 @@ def test_loop_multiplier_reads_compare_bound():
 def test_reduced_mesh_dryrun():
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                        text=True, timeout=900,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                       env={**os.environ, "PYTHONPATH": "src"})
     assert "TRAIN_OK" in r.stdout, r.stdout[-2000:] + r.stderr[-3000:]
     assert "MOE_TRAIN_OK" in r.stdout, r.stderr[-3000:]
     assert "SERVE_OK" in r.stdout, r.stderr[-3000:]

@@ -22,6 +22,7 @@ The contract under test (this PR's tentpole):
   and ``make_bucket_plan`` resolves member dtypes strictly (dtype-less
   leaves fail loudly; mixed dtypes never fuse).
 """
+import os
 import subprocess
 import sys
 import textwrap
@@ -251,11 +252,11 @@ MESH_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.core import compressor as C
-    from repro.core import compat
+    from repro.launch.mesh import make_mesh
     from repro.core import onebit_allreduce as AR
     from repro.kernels import dispatch as K
 
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     spec = P(None, "model")
     lo = C.make_layout((16, 256), spec, 4)
     vspec = C.view_spec_entries(lo, spec)
@@ -266,7 +267,7 @@ MESH_SCRIPT = textwrap.dedent("""
 
     # the partitioning rule engages: under the ambient auto mesh this
     # layout/vspec has a per-shard plan and kernel_safe keeps the kernels
-    with mesh:
+    with jax.set_mesh(mesh):
         engaged = jax.jit(lambda a: jnp.float32(
             K.kernel_safe(vspec, lo, ())))(z)
     assert float(engaged) == 1.0, "kernel_safe must keep the fused path"
@@ -274,7 +275,7 @@ MESH_SCRIPT = textwrap.dedent("""
 
     for mode in ("tensor", "chunk", "row"):
         p_ref, s_ref, e_ref = C.ef_compress(z + err, lo, mode, None)
-        with mesh:
+        with jax.set_mesh(mesh):
             fn = jax.jit(lambda a, b: K.ef_compress_view(
                 a, b, lo, mode, vspec=vspec))
             p_k, s_k, e_k = fn(jax.device_put(z, sh),
@@ -285,7 +286,7 @@ MESH_SCRIPT = textwrap.dedent("""
         np.testing.assert_allclose(np.asarray(e_k), np.asarray(e_ref),
                                    rtol=1e-5, atol=1e-6)
         v_ref = C.decompress(p_ref, s_ref, lo.pack_count)
-        with mesh:
+        with jax.set_mesh(mesh):
             v_k = jax.jit(lambda p, s: K.decompress_view(
                 p, s, lo, vspec=vspec))(p_k, s_k)
         np.testing.assert_allclose(np.asarray(v_k), np.asarray(v_ref),
@@ -296,7 +297,7 @@ MESH_SCRIPT = textwrap.dedent("""
         es = jax.random.normal(jax.random.PRNGKey(8), lo.chunk_shape) * .2
         p_ref, s_ref, e_ref = AR._server_compress((avg + es)[None], lo,
                                                   mode, None)
-        with mesh:
+        with jax.set_mesh(mesh):
             fn = jax.jit(lambda a, b, w: K.server_compress_view(
                 a, b, lo, mode, w, vspec=vspec))
             p_k, s_k, e_k = fn(jax.device_put(avg[None], sh),
@@ -313,7 +314,7 @@ MESH_SCRIPT = textwrap.dedent("""
     g, m, u = (jax.random.normal(k, lo.view_shape) for k in ks[:3])
     v = jnp.abs(jax.random.normal(ks[3], lo.view_shape)) + 1e-3
     lr, b1, eps = jnp.float32(3e-3), 0.9, 1e-8
-    with mesh:
+    with jax.set_mesh(mesh):
         fn = jax.jit(lambda g_, m_, u_, v_, lr_: K.fused_local_step_view(
             g_, m_, u_, v_, lr_, b1, eps, lo, vspec=vspec))
         mh_k, u_k, d_k = fn(jax.device_put(g, sh), jax.device_put(m, sh),
@@ -338,7 +339,7 @@ MESH_SCRIPT = textwrap.dedent("""
         lambda a, b: C.ef_compress(a + b, lo, "tensor", None),
         axis_name="w")(zw, ew)
     shw = NamedSharding(mesh, P(None, None, None, "model"))
-    with mesh:
+    with jax.set_mesh(mesh):
         fn = jax.jit(jax.vmap(lambda a, b: K.ef_compress_view(
             a, b, lo, "tensor", vspec=vspec), axis_name="w"))
         p_k, s_k, e_k = fn(jax.device_put(zw, shw),
@@ -354,7 +355,7 @@ MESH_SCRIPT = textwrap.dedent("""
     # plan, kernel_safe routes to the constrained jnp path instead
     lo2 = C.make_layout((16, 40), spec, 4)
     vs2 = C.view_spec_entries(lo2, spec)
-    with mesh:
+    with jax.set_mesh(mesh):
         safe = jax.jit(lambda a: jnp.float32(
             K.kernel_safe(vs2, lo2, ())))(z)
     assert float(safe) == 0.0, "indivisible shard must fall back"
@@ -366,8 +367,7 @@ MESH_SCRIPT = textwrap.dedent("""
 def test_sharded_dispatch_bitwise_on_mesh():
     r = subprocess.run([sys.executable, "-c", MESH_SCRIPT],
                        capture_output=True, text=True, timeout=1200,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                       env={**os.environ, "PYTHONPATH": "src"})
     out = r.stdout
     assert r.returncode == 0, out[-2000:] + r.stderr[-3000:]
     assert out.count("MODE_OK") == 3, out
