@@ -47,6 +47,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.core import bucketing as BK
 from repro.core import codecs as CODECS
 from repro.core import compressor as C
@@ -412,11 +413,12 @@ class ComposedOptimizer:
         """Full-precision mean of ONE exchange unit's member view buffers
         (the T_v / mean-round transport). Elementwise, so fusing members
         into a bucket is value-preserving per element."""
-        z = self._unit_gather(unit, bufs)
-        o = AR.fullprec_allreduce_view(
-            comm, z, self.cfg.comm_dtype, vspec=unit.vspec,
-            hierarchy=self.hierarchy, layout=unit.layout)
-        return self._unit_scatter(unit, o)
+        with jax.named_scope(telemetry.OPT_EXCHANGE):
+            z = self._unit_gather(unit, bufs)
+            o = AR.fullprec_allreduce_view(
+                comm, z, self.cfg.comm_dtype, vspec=unit.vspec,
+                hierarchy=self.hierarchy, layout=unit.layout)
+            return self._unit_scatter(unit, o)
 
     def _fullprec_dp(self, comm, bufs_dp):
         """Full-precision mean of the DP leaves' view buffers, one
@@ -437,10 +439,13 @@ class ComposedOptimizer:
     # ------------------------------------------------------------------ #
     def step(self, comm: Comm, params, grads, state: CompressedDPState,
              worker_index=None):
-        if self.cfg.style == "accumulate":
-            return self._step_accumulate(comm, params, grads, state,
-                                         worker_index)
-        return self._step_sync(comm, params, grads, state, worker_index)
+        # every op of the step is the local step's unless the sync, the
+        # variance round or the exchange inside it names its own scope
+        with jax.named_scope(telemetry.OPT_LOCAL_STEP):
+            if self.cfg.style == "accumulate":
+                return self._step_accumulate(comm, params, grads, state,
+                                             worker_index)
+            return self._step_sync(comm, params, grads, state, worker_index)
 
     # --- accumulate: paper Algorithm 1, generalized over bases ---------- #
     def _step_accumulate(self, comm, params, grads, state, worker_index):
@@ -546,6 +551,7 @@ class ComposedOptimizer:
                               for name in sync_names)
                         for i in unit.members))
 
+            @jax.named_scope(telemetry.OPT_SYNC_UPDATE)
             def sync_b(op):
                 xh_m, mh_m, uh_m, ew, es, anc, _ = op
                 z = self._unit_gather(unit, list(uh_m))
@@ -609,6 +615,7 @@ class ComposedOptimizer:
         # --- T_v: full-precision variance refresh, also per unit -------
         if base.has_variance:
             def unit_var_cond(unit):
+                @jax.named_scope(telemetry.OPT_VAR_ROUND)
                 def var_b(vs_m):
                     gbars = self._fullprec_unit(
                         comm, unit, [gv[i] for i in unit.members])
@@ -696,6 +703,7 @@ class ComposedOptimizer:
                     return (tuple(o.astype(jnp.float32) for o in outs),
                             ew, es)
 
+                @jax.named_scope(telemetry.OPT_SYNC_UPDATE)
                 def onebit_b(op):
                     gs_m, ew, es = op
                     z = self._unit_gather(unit, list(gs_m))

@@ -31,6 +31,7 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.core import codecs as CODECS
 from repro.core import compressor as C
 from repro.core.codecs import _server_compress  # noqa: F401 (moved to
@@ -100,6 +101,7 @@ def _use_kernels(cfg: OneBitConfig, vspec, layout=None) -> bool:
                                                        cfg.model_axes)
 
 
+@jax.named_scope(telemetry.OPT_ENCODE)
 def _flat_worker_encode(z_view, ef: EFState, layout, cfg, vspec):
     """Flat worker phase: codec encode of this worker's full view.
 
@@ -131,16 +133,19 @@ def _flat_server_encode(recv, ef: EFState, layout, cfg, vspec, mask, use_k,
     the chunk this worker serves. Returns ``(payload_s, err_s)``."""
     codec = cfg.codec
     cst = lambda x: C.constrain(x, vspec)
-    vals = codec.decode(recv, layout, cfg.compute_dtype, use_pallas=use_k,
-                        vspec=vspec)
-    avg = cst(vals).mean(axis=0)                              # (A/n, *rest)
-    s_mask = None if mask is None else mask[widx][None]
-    return codec.encode_server(
-        avg, ef.err_server if codec.needs_ef else None, layout,
-        cfg.scale_mode, s_mask, widx, cfg.model_axes, use_pallas=use_k,
-        cst=cst, vspec=vspec)
+    with jax.named_scope(telemetry.OPT_DECODE):
+        vals = codec.decode(recv, layout, cfg.compute_dtype,
+                            use_pallas=use_k, vspec=vspec)
+        avg = cst(vals).mean(axis=0)                          # (A/n, *rest)
+    with jax.named_scope(telemetry.OPT_ENCODE):
+        s_mask = None if mask is None else mask[widx][None]
+        return codec.encode_server(
+            avg, ef.err_server if codec.needs_ef else None, layout,
+            cfg.scale_mode, s_mask, widx, cfg.model_axes, use_pallas=use_k,
+            cst=cst, vspec=vspec)
 
 
+@jax.named_scope(telemetry.OPT_EXCHANGE)
 def _map_a2a(comm, payload, vspec):
     # every payload leaf carries the chunk axis first -> rows become the
     # sender index after the all_to_all.
@@ -150,6 +155,7 @@ def _map_a2a(comm, payload, vspec):
         payload)
 
 
+@jax.named_scope(telemetry.OPT_EXCHANGE)
 def _map_gather(comm, payload, vspec):
     cst = lambda x: C.constrain(x, vspec)
     return jax.tree.map(
@@ -198,14 +204,18 @@ def onebit_allreduce_view(comm: Comm, z_view: jnp.ndarray, ef: EFState,
 
     # --- gather: broadcast compressed chunk results -------------------------
     gathered = _map_gather(comm, payload_s, vspec)
-    out = cst(codec.decode(gathered, layout, cfg.compute_dtype,
-                           use_pallas=use_k, vspec=vspec))
+    with jax.named_scope(telemetry.OPT_DECODE):
+        out = cst(codec.decode(gathered, layout, cfg.compute_dtype,
+                               use_pallas=use_k, vspec=vspec))
+        out = out.astype(cfg.compute_dtype)
     if codec.needs_ef:
-        ef = EFState(err_worker=cst(err_w).astype(ef.err_worker.dtype),
-                     err_server=err_s.astype(ef.err_server.dtype))
-    return out.astype(cfg.compute_dtype), ef
+        with jax.named_scope(telemetry.OPT_ENCODE):
+            ef = EFState(err_worker=cst(err_w).astype(ef.err_worker.dtype),
+                         err_server=err_s.astype(ef.err_server.dtype))
+    return out, ef
 
 
+@jax.named_scope(telemetry.OPT_EXCHANGE)
 def _hier_reduce_scatter(inner, z_view, layout, cfg, vspec):
     """Hier step 1: intra-pod reduce-scatter. Returns this worker's own
     slice (inner index j)."""
@@ -222,6 +232,7 @@ def _hier_reduce_scatter(inner, z_view, layout, cfg, vspec):
     return cst(own.astype(cfg.compute_dtype))
 
 
+@jax.named_scope(telemetry.OPT_ENCODE)
 def _hier_worker_encode(own, ef: EFState, layout, cfg, vspec, j):
     """Hier step 2a: codec encode of the owned slice.
 
@@ -250,16 +261,19 @@ def _hier_server_encode(recv, ef: EFState, layout, cfg, vspec, mask_full,
     ``widx = j * n_outer + k``. Returns ``(payload_s, err_s)``."""
     codec = cfg.codec
     cst = lambda x: C.constrain(x, vspec)
-    vals = codec.decode(recv, layout, cfg.compute_dtype, use_pallas=use_k,
-                        vspec=vspec)
-    avg = cst(vals).mean(axis=0)                           # (A/n, *rest)
-    s_mask = None if mask_full is None else mask_full[widx][None]
-    return codec.encode_server(
-        avg, ef.err_server if codec.needs_ef else None, layout,
-        cfg.scale_mode, s_mask, widx, cfg.model_axes, use_pallas=use_k,
-        cst=cst, vspec=vspec)
+    with jax.named_scope(telemetry.OPT_DECODE):
+        vals = codec.decode(recv, layout, cfg.compute_dtype,
+                            use_pallas=use_k, vspec=vspec)
+        avg = cst(vals).mean(axis=0)                       # (A/n, *rest)
+    with jax.named_scope(telemetry.OPT_ENCODE):
+        s_mask = None if mask_full is None else mask_full[widx][None]
+        return codec.encode_server(
+            avg, ef.err_server if codec.needs_ef else None, layout,
+            cfg.scale_mode, s_mask, widx, cfg.model_axes, use_pallas=use_k,
+            cst=cst, vspec=vspec)
 
 
+@jax.named_scope(telemetry.OPT_EXCHANGE)
 def _hier_gather_out(inner, out_slice, layout, cfg, vspec):
     """Hier step 3: intra-pod all_gather rebuilds the full view."""
     cst = lambda x: C.constrain(x, vspec)
@@ -320,11 +334,14 @@ def _hier_allreduce_view(comm: Comm, z_view: jnp.ndarray, ef: EFState,
 
     # --- 2d: inter-pod gather of the compressed chunk results ---------------
     gathered = _map_gather(outer, payload_s, vspec)
-    out_slice = cst(codec.decode(gathered, layout, cfg.compute_dtype,
-                                 use_pallas=use_k, vspec=vspec))
+    with jax.named_scope(telemetry.OPT_DECODE):
+        out_slice = cst(codec.decode(gathered, layout, cfg.compute_dtype,
+                                     use_pallas=use_k, vspec=vspec))
     if codec.needs_ef:
-        new_ef = EFState(err_worker=cst(err_w).astype(ef.err_worker.dtype),
-                         err_server=err_s.astype(ef.err_server.dtype))
+        with jax.named_scope(telemetry.OPT_ENCODE):
+            new_ef = EFState(
+                err_worker=cst(err_w).astype(ef.err_worker.dtype),
+                err_server=err_s.astype(ef.err_server.dtype))
     else:
         new_ef = ef
 

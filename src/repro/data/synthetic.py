@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
+
 
 @dataclasses.dataclass(frozen=True)
 class DataConfig:
@@ -42,6 +44,10 @@ class SyntheticLM:
         self.table = jnp.asarray(_bigram_table(cfg.vocab, cfg.seed))
 
     def batch(self, step: int) -> Dict[str, jnp.ndarray]:
+        with telemetry.span("data.batch"):
+            return self._batch(step)
+
+    def _batch(self, step: int) -> Dict[str, jnp.ndarray]:
         cfg = self.cfg
         key = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), step)
         B, S = cfg.global_batch, cfg.seq_len

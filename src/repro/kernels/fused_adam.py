@@ -80,6 +80,7 @@ def fused_local_step(g, m, u, v, lr, beta1, *, eps=1e-8,
             jax.ShapeDtypeStruct((R, C), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_local_step",
     )(g, m, u, v, lr_arr, b1_arr, omb1_arr)
 
 
@@ -125,4 +126,5 @@ def fused_local_step_sgd(g, m, u, lr, beta1, *, block=(8, 1024),
             jax.ShapeDtypeStruct((R, C), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_local_step_sgd",
     )(g, m, u, lr_arr, b1_arr, omb1_arr)

@@ -162,6 +162,7 @@ def ef_compress(z: jnp.ndarray, err: jnp.ndarray, counts=None, *,
             jax.ShapeDtypeStruct((R, C), err.dtype),
         ],
         interpret=interpret,
+        name="ef_compress",
     )(z, err, _counts(counts, R, C))
     return packed, scales.reshape(R), err_out
 
@@ -188,6 +189,7 @@ def abs_rowsum(z: jnp.ndarray, err: jnp.ndarray, counts=None, *,
         out_specs=row(1),
         out_shape=jax.ShapeDtypeStruct((R, 1), jnp.float32),
         interpret=interpret,
+        name="abs_rowsum",
     )(z, err, _counts(counts, R, C)).reshape(R)
 
 
@@ -220,6 +222,7 @@ def ef_quantize(z: jnp.ndarray, err: jnp.ndarray, scales: jnp.ndarray,
             jax.ShapeDtypeStruct((R, C), err.dtype),
         ],
         interpret=interpret,
+        name="ef_quantize",
     )(z, err, _col(scales, R), _counts(counts, R, C))
 
 
@@ -251,4 +254,5 @@ def decompress(packed: jnp.ndarray, scales: jnp.ndarray, *,
         out_specs=row(CB * 8),
         out_shape=jax.ShapeDtypeStruct((R, CB * 8), dtype),
         interpret=interpret,
+        name="decompress",
     )(packed, _col(scales, R))

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.checkpointing import io as ckpt_io
 from repro.configs import get
 from repro.core import (CODEC_NAMES, Hierarchy, OptimizerConfig,
@@ -156,11 +157,15 @@ def parse_args(argv=None):
 
 def run(args, opt_cfg=None, on_step=None):
     """Train as the CLI does. Returns the per-step record — ``loss``,
-    ``synced``, ``var_round`` and ``step_s`` (wall seconds of each step,
-    the first one including compilation) — with the final ``params``,
-    ``state`` and the ``trainer``. ``opt_cfg`` overrides the optimizer
-    config ``args`` would build; ``on_step(step, params, state)``, if
-    given, is called after each step."""
+    ``synced``, ``var_round``, ``step_s`` (wall seconds of each step,
+    the first one including compilation) and ``compiles`` (the host's
+    compile-or-load requests during each step, ``telemetry.
+    CompileCounters``) — with the final ``params``, ``state`` and the
+    ``trainer``. ``opt_cfg`` overrides the optimizer config ``args``
+    would build; ``on_step(step, params, state)``, if given, is called
+    after each step. Each step runs under the profiler's ``train`` step
+    annotation, with ``train.batch``, ``train.step`` (the call and
+    ``block_until_ready``) and ``train.read`` spans inside."""
     spec = get(args.arch)
     cfg = spec.smoke if args.smoke else spec.config
     opt_cfg = opt_cfg or build_opt_cfg(args)
@@ -215,25 +220,36 @@ def run(args, opt_cfg=None, on_step=None):
                                   kind="lm" if cfg.causal else "mlm"))
 
     first = lambda x: np.asarray(x).reshape(-1)[0]
-    rec = {"loss": [], "synced": [], "var_round": [], "step_s": []}
+    rec = {"loss": [], "synced": [], "var_round": [], "step_s": [],
+           "compiles": []}
+    counters = telemetry.CompileCounters.install()
+    after_first = counters.snapshot()
     t0 = time.time()
     comp_bytes = 0.0
     rounds = 0
     for step in range(args.steps):
-        batch = data.batch(step)
-        if cfg.enc_layers:
-            batch["frames"] = jnp.zeros((args.batch, cfg.enc_frames,
-                                         cfg.d_model))
-        if cfg.vision_tokens:
-            batch["vision_embeds"] = jnp.zeros(
-                (args.batch, cfg.vision_tokens, cfg.d_model))
-        ts = time.perf_counter()
-        params, state, met = step_fn(params, state, batch)
-        jax.block_until_ready((params, state, met))
-        rec["step_s"].append(time.perf_counter() - ts)
-        synced = bool(first(met["synced"]))
-        var_r = bool(first(met["var_round"]))
-        loss = float(first(met["loss"]))
+        snap = counters.snapshot()
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            with telemetry.span("train.batch"):
+                batch = data.batch(step)
+                if cfg.enc_layers:
+                    batch["frames"] = jnp.zeros((args.batch, cfg.enc_frames,
+                                                 cfg.d_model))
+                if cfg.vision_tokens:
+                    batch["vision_embeds"] = jnp.zeros(
+                        (args.batch, cfg.vision_tokens, cfg.d_model))
+            ts = time.perf_counter()
+            with telemetry.span("train.step"):
+                params, state, met = step_fn(params, state, batch)
+                jax.block_until_ready((params, state, met))
+            rec["step_s"].append(time.perf_counter() - ts)
+            with telemetry.span("train.read"):
+                synced = bool(first(met["synced"]))
+                var_r = bool(first(met["var_round"]))
+                loss = float(first(met["loss"]))
+        rec["compiles"].append(counters.since(snap)["compiles"])
+        if step == 0:
+            after_first = counters.snapshot()
         rec["loss"].append(loss)
         rec["synced"].append(synced)
         rec["var_round"].append(var_r)
@@ -252,9 +268,11 @@ def run(args, opt_cfg=None, on_step=None):
                   f"[{time.time()-t0:.1f}s]")
 
     bits_pp = 8 * comp_bytes / max(acct["dp_params"], 1) / max(args.steps, 1)
+    later = counters.since(after_first)
     print(f"DONE: {args.steps} steps, {rounds} comm rounds, "
-          f"avg {bits_pp:.3f} bits/param/step "
-          f"({time.time()-t0:.1f}s)")
+          f"avg {bits_pp:.3f} bits/param/step, "
+          f"{later['compiles']} compiles ({later['seconds']['compile']:.2f}s)"
+          f" after the first step ({time.time()-t0:.1f}s)")
     if args.save:
         ckpt_io.save(args.save, {"params": params, "state": state},
                      step=args.steps,

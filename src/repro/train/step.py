@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import telemetry
 from repro.core import api as opt_api
 from repro.core.comm import (Comm, NullComm, mesh_comm, norm_hierarchy,
                              sim_comm)
@@ -120,11 +121,16 @@ def accumulate_grads(loss_fn, params, batch, micro_batches, *, peel=True):
 
 
 class Trainer:
-    """Holds the static plan: templates, specs, optimizer, jitted step."""
+    """Holds the static plan: templates, specs, optimizer, jitted step.
+
+    Building one installs the process's compile counters
+    (``telemetry.CompileCounters``), so a process that trains counts the
+    host's compiles from its first step on."""
 
     def __init__(self, model_cfg: ModelConfig, opt_cfg, *, mesh=None,
                  n_workers: Optional[int] = None,
                  trainer_cfg: TrainerConfig = TrainerConfig()):
+        telemetry.CompileCounters.install()
         self.model_cfg = model_cfg
         self.opt_cfg = opt_cfg
         self.mesh = mesh
@@ -313,8 +319,9 @@ class Trainer:
             loss, met = T.lm_loss(p_, self.model_cfg, b_, comm=ep_comm)
             return loss, met
 
-        loss, grads = accumulate_grads(
-            loss_fn, p, batch, mb, peel=self.tc.peel_last_microbatch)
+        with jax.named_scope(telemetry.MODEL_FWD_BWD):
+            loss, grads = accumulate_grads(
+                loss_fn, p, batch, mb, peel=self.tc.peel_last_microbatch)
 
         grads = self._ep_scale_grads(grads, comm)
         widx = (comm.index() if not isinstance(comm, NullComm)
