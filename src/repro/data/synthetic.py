@@ -10,6 +10,7 @@ needs (each worker slices its own batch shard by index).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict
 
 import jax
@@ -36,47 +37,58 @@ def _bigram_table(vocab: int, seed: int) -> np.ndarray:
     return rng.randint(0, vocab, size=(vocab, 4)).astype(np.int32)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "global_batch", "seq_len", "kind", "mlm_mask_frac"))
+def _lm_batch(table, seed_key, step, *, global_batch: int, seq_len: int,
+              kind: str, mlm_mask_frac: float):
+    """One batch, from ``(seed, step)`` to the output dict, as one program
+    per batch shape and kind. The seed's key and the step are arguments, so
+    a new seed or step compiles nothing."""
+    key = jax.random.fold_in(seed_key, step)
+    B, S, vocab = global_batch, seq_len, table.shape[0]
+    k1, k2, k3 = jax.random.split(key, 3)
+    first = jax.random.randint(k1, (B,), 0, vocab)
+    choice = jax.random.randint(k2, (B, S), 0, 4)
+    noise = jax.random.bernoulli(k3, 0.1, (B, S))
+    nz = jax.random.randint(jax.random.fold_in(k3, 1), (B, S), 0, vocab)
+
+    def step_fn(tok, xs):
+        ch, nv, nzv = xs
+        nxt = jnp.where(nv, nzv, table[tok, ch])
+        return nxt, nxt
+
+    _, toks = jax.lax.scan(step_fn, first, (choice.T, noise.T, nz.T))
+    tokens = jnp.concatenate([first[:, None], toks.T[:, :-1]], axis=1)
+    labels = toks.T
+    out = {"tokens": tokens.astype(jnp.int32),
+           "labels": labels.astype(jnp.int32)}
+    if kind == "mlm":
+        km = jax.random.fold_in(key, 99)
+        mask = jax.random.bernoulli(km, mlm_mask_frac, (B, S))
+        out["labels"] = out["tokens"]
+        out["tokens"] = jnp.where(mask, 0, out["tokens"])  # 0 = [MASK]
+        out["loss_mask"] = mask.astype(jnp.float32)
+    return out
+
+
 class SyntheticLM:
-    """Latent bigram LM stream; ~2 bits of predictable structure/token."""
+    """Latent bigram LM stream; ~2 bits of predictable structure/token.
+
+    ``batch`` dispatches one jitted program and returns without waiting;
+    instances with equal batch shapes and kind share its executable."""
 
     def __init__(self, cfg: DataConfig):
         self.cfg = cfg
         self.table = jnp.asarray(_bigram_table(cfg.vocab, cfg.seed))
+        self.seed_key = jax.random.PRNGKey(cfg.seed)
 
     def batch(self, step: int) -> Dict[str, jnp.ndarray]:
-        with telemetry.span("data.batch"):
-            return self._batch(step)
-
-    def _batch(self, step: int) -> Dict[str, jnp.ndarray]:
         cfg = self.cfg
-        key = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), step)
-        B, S = cfg.global_batch, cfg.seq_len
-        k1, k2, k3 = jax.random.split(key, 3)
-        first = jax.random.randint(k1, (B,), 0, cfg.vocab)
-        choice = jax.random.randint(k2, (B, S), 0, 4)
-        noise = jax.random.bernoulli(k3, 0.1, (B, S))
-        nz = jax.random.randint(jax.random.fold_in(k3, 1), (B, S), 0,
-                                cfg.vocab)
-
-        def step_fn(tok, xs):
-            ch, nv, nzv = xs
-            nxt = jnp.where(nv, nzv, self.table[tok, ch])
-            return nxt, nxt
-
-        _, toks = jax.lax.scan(
-            step_fn, first,
-            (choice.T, noise.T, nz.T))
-        tokens = jnp.concatenate([first[:, None], toks.T[:, :-1]], axis=1)
-        labels = toks.T
-        out = {"tokens": tokens.astype(jnp.int32),
-               "labels": labels.astype(jnp.int32)}
-        if cfg.kind == "mlm":
-            km = jax.random.fold_in(key, 99)
-            mask = jax.random.bernoulli(km, cfg.mlm_mask_frac, (B, S))
-            out["labels"] = out["tokens"]
-            out["tokens"] = jnp.where(mask, 0, out["tokens"])  # 0 = [MASK]
-            out["loss_mask"] = mask.astype(jnp.float32)
-        return out
+        with telemetry.span("data.batch"):
+            return _lm_batch(self.table, self.seed_key, step,
+                             global_batch=cfg.global_batch,
+                             seq_len=cfg.seq_len, kind=cfg.kind,
+                             mlm_mask_frac=cfg.mlm_mask_frac)
 
 
 class SyntheticClassify:
