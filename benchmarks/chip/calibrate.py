@@ -6,12 +6,12 @@
 
 For each seed, in this one process: the program's readings from the
 cell's set-up steps (the window's own call and feed, at the cell's size),
-then the plain reference (float32, matmuls at ``highest``), and each
-variant put in the program's place: ``control`` (the reference with the
-model, parameters and optimizer state in bfloat16), ``half_batch`` (each
-worker's loss from half its rows) and ``no_exchange`` (nothing crosses
-between the workers). Each line is one variant's numbers against the
-reference, as ``run.py`` compares them.
+then the configuration's plain reference (float32, matmuls at
+``highest``), and each variant put in the program's place: ``control``
+(the reference with the model, parameters and optimizer state in
+bfloat16), ``half_batch`` (each worker's loss from half its rows) and
+``no_exchange`` (nothing crosses between the workers). Each line is one
+variant's numbers against the reference, as ``run.py`` compares them.
 Needs the chips the cell asks for.
 """
 from __future__ import annotations
@@ -31,7 +31,6 @@ for p in (str(ROOT / "src"), str(ROOT)):
 
 import jax  # noqa: E402
 
-from benchmarks.chip import reference as R  # noqa: E402
 from benchmarks.chip import run as RUN  # noqa: E402
 
 
@@ -47,6 +46,7 @@ def program_readings(cell: dict, seed: int) -> dict:
 def readings(cell: dict, seed: int, variants) -> tuple:
     """{variant: numbers}, and the raw readings."""
     chips = cell["workload"]["chips"]
+    R = cell["reference"]
     m, opt, mix = (cell["config"]["model"], cell["config"]["optimizer"],
                    cell["mix"])
     devs = jax.devices()[:chips]

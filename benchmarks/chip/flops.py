@@ -1,4 +1,6 @@
-"""Operations and bytes the benchmark's metrics divide by, from shapes alone.
+"""Operations and bytes the benchmark's metrics divide by, from shapes alone:
+the counts module (``benchmarks/chip/__init__.py``) of the dense pre-LN
+transformer that ``bert-large`` and ``gpt2`` run.
 
 Two counts, both from a configuration file's ``model`` block:
 
@@ -12,7 +14,8 @@ Two counts, both from a configuration file's ``model`` block:
 * ``optimizer_bytes``: the HBM bytes one step of 0/1 Adam must move in the
   optimizer, f32 state, per step kind: every state buffer the step reads,
   once, and every one it writes, once. Packed sign bits and scales are
-  left out, so this is a lower bound on the traffic.
+  left out, so this is a lower bound on the traffic. Every leaf of this
+  model takes the data-parallel path.
 """
 from __future__ import annotations
 
@@ -90,9 +93,5 @@ def optimizer_bytes_per_param(kind: str, n_workers: int) -> float:
     return float(b)
 
 
-def optimizer_bytes(n_params: int, n_workers: int, kind: str) -> float:
-    return n_params * optimizer_bytes_per_param(kind, n_workers)
-
-
-def step_kind(synced: bool, var_round: bool) -> str:
-    return ("sync" if synced else "local") + ("+var" if var_round else "")
+def optimizer_bytes(m: dict, n_workers: int, kind: str) -> float:
+    return param_count(m) * optimizer_bytes_per_param(kind, n_workers)

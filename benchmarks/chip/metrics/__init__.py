@@ -2,13 +2,24 @@
 value, or None where the run holds nothing to read it from.
 
 ``ctx`` (built by ``run.py`` in a ``--trace 1`` run): ``trace`` (the
-summary of ``trace.summarize``), ``spans`` ((batch start, step start,
-read end) host times of each traced step), ``kinds`` (step kind of each
-traced step), ``window_s`` and ``tokens`` of the traced window (host
-clock), ``chips``, ``model`` and ``mix`` (the configuration's model block
-and the mix), ``peaks`` (``peaks.json``), ``device_kind``,
-``memory_peak_bytes`` and ``n_params``.
+summary of ``trace.summarize``), ``trace_path`` (the profiler's
+``.xplane.pb``, there until every reader has run), ``hlo`` (the optimized
+step program's text), ``anchors`` (the bounds of two
+``telemetry.clock_anchor()``s, one after the trace starts and one before
+it stops), ``spans`` ((batch start,
+step start, read end) host times of each traced step), ``kinds`` (step
+kind of each traced step, ``step_kind``), ``window_s`` and ``tokens`` of
+the traced window (host clock), ``chips``, ``model`` and ``mix`` (the
+configuration's model block and the mix), ``counts`` (the
+configuration's counts module), ``peaks`` (``peaks.json``),
+``device_kind`` and ``memory_peak_bytes``.
 """
+
+
+def step_kind(synced: bool, var_round: bool) -> str:
+    """The kind of one step, as the counts modules' ``optimizer_bytes``
+    take it: ``local`` or ``sync``, with ``+var`` on a variance round."""
+    return ("sync" if synced else "local") + ("+var" if var_round else "")
 
 
 def peak(ctx: dict) -> dict:

@@ -10,23 +10,18 @@ weights and optimizer state on the chips from the seed
 (``Trainer.mesh_init``), and steps it as ``repro.launch.train.run`` does:
 ``SyntheticLM.batch``, the step, ``block_until_ready``, then reading the
 loss and the step kind. Set-up runs the mix's first steps, whose readings
-the correctness check compares with ``reference.py``; the window then
-measures for ``--seconds``. With ``--trace 1`` the profiler records the
-mix's ``trace_steps`` window steps and the per-layer metrics come from
-that trace. The last line of standard output is the result as JSON; the
-numbers compared, each with its limit, are the last lines of standard
-error and the result's last key.
+the correctness check compares with the configuration's reference module;
+the window then measures for ``--seconds``. With ``--trace 1`` the
+profiler records the mix's ``trace_steps`` window steps and the per-layer
+metrics come from that trace. The last line of standard output is the
+result as JSON; the numbers compared, each with its limit, are the last
+lines of standard error and the result's last key.
 
 The compile cache is the checkout's own ``.jax_cache/`` (the directory
 ``repro.launch.cache`` uses when ``JAX_COMPILATION_CACHE_DIR`` is unset),
-and the whole run persists every program it compiles, however quick.
-``SyntheticLM.batch`` traces its scan anew on every call; under JAX's
-default threshold of one second that scan is written to the cache only
-when one of its compiles happens to take longer, after which every step
-loads it from disk. A long run of ``repro.launch.train`` reaches that
-state at its first slow compile; the benchmark starts in it, so that
-whether the window compiles the scan or loads it does not depend on the
-host's load in an earlier run.
+and the whole run persists every program it compiles, however quick, so
+that a warm run's set-up loads every program it needs from the cache
+whatever each one's compile time was.
 
 The run exits 3 and prints no result unless JAX's backend is a TPU with
 exactly the chips the cell asks for.
@@ -44,6 +39,7 @@ import importlib  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import pathlib  # noqa: E402
+import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 
@@ -60,8 +56,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from benchmarks.chip import flops as F  # noqa: E402
-from benchmarks.chip import reference as R  # noqa: E402
+from benchmarks.chip.metrics import step_kind  # noqa: E402
 
 
 class NoChip(RuntimeError):
@@ -72,9 +67,17 @@ class NoChip(RuntimeError):
 # the manifest and the files it names
 # --------------------------------------------------------------------------
 
+def chip_module(name: str):
+    """The module ``benchmarks/chip/<name>.py``."""
+    if not name.isidentifier():
+        raise ValueError(f"{name!r} names no module of benchmarks/chip")
+    return importlib.import_module(f"benchmarks.chip.{name}")
+
+
 def load_cell(workload: str, root: pathlib.Path = ROOT) -> dict:
     """The workload's entry with its configuration, mix, per-layer metrics
-    and limits, each read from the file its name gives."""
+    and limits, each read from the file its name gives, and the
+    configuration's reference and counts modules."""
     man = json.loads((root / "BENCHMARK.json").read_text())
     wl = {w["name"]: w for w in man["workloads"]}.get(workload)
     if wl is None:
@@ -86,9 +89,12 @@ def load_cell(workload: str, root: pathlib.Path = ROOT) -> dict:
                if workload in m.get("workloads", [workload])]
     e2e = [m for m in man["end_to_end"]
            if workload in m.get("workloads", [workload])]
+    config = json.loads((root / cfg["file"]).read_text())
     return {
         "workload": wl,
-        "config": json.loads((root / cfg["file"]).read_text()),
+        "config": config,
+        "reference": chip_module(config["reference"]),
+        "counts": chip_module(config["counts"]),
         "mix": json.loads((here / "mixes" / f"{wl['traffic']}.json")
                           .read_text()),
         "end_to_end": e2e,
@@ -154,6 +160,7 @@ class Cell:
         from repro.train import Trainer, TrainerConfig
         from repro.launch.mesh import make_local_mesh, worker_axes
         self.cell, self.seed = cell, seed
+        self.ref = cell["reference"]
         self.m = cell["config"]["model"]
         self.opt = cell["config"]["optimizer"]
         self.mix = cell["mix"]
@@ -176,6 +183,10 @@ class Cell:
             global_batch=self.batch_rows, seed=seed % 2 ** 32,
             kind="lm" if self.m.get("causal", True) else "mlm"))
         self.paths = leaf_paths(self.params)
+        # whether each leaf carries the leading worker axis: leaves that take
+        # the data-parallel path do; expert leaves, split over the workers,
+        # do not
+        self.dp = [pd.dp for pd in self.tr.pd_leaves]
         self.t = 0
         self.readings = {"loss": []}
 
@@ -200,12 +211,17 @@ class Cell:
         self.t += 1
         return loss, synced, var_r
 
+    def worker0(self, xs):
+        """Worker 0's copy of each leaf: the first row of a leaf with the
+        worker axis, the whole of one without."""
+        return [x[0] if dp else x for x, dp in zip(xs, self.dp)]
+
     def grad_norms_from_state(self):
         """Per-leaf norm of the first gradient as the optimizer received it:
         after one variance round from v = 0, v = (1 - b2) gbar^2."""
         b2 = self.opt["beta2"]
         vs = self.state.slots["v"]
-        sums = jax.jit(lambda v: [jnp.sum(x[0]) for x in v])(vs)
+        sums = jax.jit(lambda v: [jnp.sum(x) for x in self.worker0(v)])(vs)
         return {p: math.sqrt(float(s) / (1 - b2))
                 for p, s in zip(self.paths, sums)}
 
@@ -214,9 +230,10 @@ class Cell:
         m = self.m
 
         def f(params, key):
-            x0 = R.init_params(m, key)
-            return [jnp.sqrt(jnp.sum(jnp.square(x[0] - x0[p])))
-                    for p, x in zip(self.paths, jax.tree.leaves(params))]
+            x0 = self.ref.init_params(m, key)
+            xs = self.worker0(jax.tree.leaves(params))
+            return [jnp.sqrt(jnp.sum(jnp.square(x - x0[p])))
+                    for p, x in zip(self.paths, xs)]
 
         out = jax.jit(f)(self.params, self.key)
         return {p: float(v) for p, v in zip(self.paths, out)}
@@ -227,7 +244,7 @@ class Cell:
         kinds = []
         for i in range(self.mix["setup_steps"]):
             loss, synced, var_r = self.step()
-            kinds.append(F.step_kind(synced, var_r))
+            kinds.append(step_kind(synced, var_r))
             if i < 3:
                 self.readings["loss"].append(loss)
             if i == 0:
@@ -286,10 +303,13 @@ def _run_cell(cell, seed, seconds, trace, wrap_step, log):
         f"{time.perf_counter() - T_START:.1f} s after start")
 
     spans, kinds, losses = [], [], []
-    tracer = None
+    tracer = anchors = None
     if trace:
+        from repro import telemetry
         tracer = tempfile.mkdtemp(prefix="bench_trace_")
         jax.profiler.start_trace(tracer)
+        # put the program's wall-clock counters on the trace's clock
+        anchors = [telemetry.clock_anchor()]
         n_max = c.mix["trace_steps"]
     else:
         n_max = None
@@ -298,13 +318,14 @@ def _run_cell(cell, seed, seconds, trace, wrap_step, log):
     while True:
         loss, synced, var_r = c.step(spans)
         losses.append(loss)
-        kinds.append(F.step_kind(synced, var_r))
+        kinds.append(step_kind(synced, var_r))
         done = spans[-1][2] - t_window >= seconds
         if n_max is not None and len(kinds) >= n_max:
             done = True
         if done:
             break
     if tracer:
+        anchors.append(telemetry.clock_anchor())
         jax.profiler.stop_trace()
     window_s = spans[-1][2] - t_window
     counts = {k: kinds.count(k) for k in sorted(set(kinds))}
@@ -337,33 +358,35 @@ def _run_cell(cell, seed, seconds, trace, wrap_step, log):
             metrics[m["name"]] = {"value": vals[m["name"]], "unit": m["unit"]}
     else:
         from benchmarks.chip import trace as TR
-        # the optimized step program: its module name and each op's JAX
-        # name stack, which the trace's op events do not carry
-        compiled = c.step_fn.lower(c.params, c.state,
-                                   c.data.batch(c.t)).compile()
-        hlo = compiled.as_text()
-        log(f"step program memory (the chip's compiler): "
-            f"{compiled.memory_analysis()}")
-        log(f"memory_stats: {devices[0].memory_stats()}")
-        summ = TR.summarize(TR.find_xplane(tracer), len(kinds), chips,
-                            hlo=hlo)
-        log(f"trace: {summ['step_runs']:g} executions of "
-            f"{summ['step_module']} in the window, per step (s) "
-            f"{summ['per_step_s']}")
-        device["busy_s"] = summ["busy_s"]
-        device["window_s"] = summ["window_s"]
-        breakdown = summ["breakdown"]
-        ctx = {"trace": summ, "spans": spans, "kinds": kinds,
-               "window_s": window_s, "tokens": tokens, "chips": chips,
-               "model": c.m, "mix": c.mix, "peaks": cell["peaks"],
-               "device_kind": dev.device_kind, "memory_peak_bytes": peak,
-               "n_params": F.param_count(c.m)}
-        for m in cell["per_layer"]:
-            v = metric_reader(m["name"]).read(ctx)
-            if v is not None:
-                metrics[m["name"]] = {"value": v, "unit": m["unit"]}
-        import shutil
-        shutil.rmtree(tracer, ignore_errors=True)
+        try:
+            # the optimized step program: its module name and each op's JAX
+            # name stack, which the trace's op events do not carry
+            compiled = c.step_fn.lower(c.params, c.state,
+                                       c.data.batch(c.t)).compile()
+            hlo = compiled.as_text()
+            log(f"step program memory (the chip's compiler): "
+                f"{compiled.memory_analysis()}")
+            log(f"memory_stats: {devices[0].memory_stats()}")
+            path = TR.find_xplane(tracer)
+            summ = TR.summarize(path, len(kinds), chips, hlo=hlo)
+            log(f"trace: {summ['step_runs']:g} executions of "
+                f"{summ['step_module']} in the window, per step (s) "
+                f"{summ['per_step_s']}")
+            device["busy_s"] = summ["busy_s"]
+            device["window_s"] = summ["window_s"]
+            breakdown = summ["breakdown"]
+            ctx = {"trace": summ, "trace_path": path, "hlo": hlo,
+                   "anchors": anchors, "spans": spans, "kinds": kinds,
+                   "window_s": window_s, "tokens": tokens, "chips": chips,
+                   "model": c.m, "mix": c.mix, "counts": cell["counts"],
+                   "peaks": cell["peaks"], "device_kind": dev.device_kind,
+                   "memory_peak_bytes": peak}
+            for m in cell["per_layer"]:
+                v = metric_reader(m["name"]).read(ctx)
+                if v is not None:
+                    metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+        finally:
+            shutil.rmtree(tracer, ignore_errors=True)
     readings = c.readings
     c.free()
     del c
@@ -371,6 +394,7 @@ def _run_cell(cell, seed, seconds, trace, wrap_step, log):
 
     # the check: the plain reference over the same first steps, after the
     # program's state is gone and the peak has been read
+    R = cell["reference"]
     t_ref = time.perf_counter()
     ref = R.run(cell["config"]["model"], cell["config"]["optimizer"],
                 cell["mix"], seed, chips, devices=devices)

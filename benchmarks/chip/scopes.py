@@ -8,6 +8,9 @@ scopes and the host's compile counters (``repro.telemetry``).
   ``unscoped``. The values sum to ``per_step_s["optimizer"]`` of
   ``trace.summarize`` on the same trace. ``unscoped_ops`` lists those
   ops by instruction and opcode.
+- ``optimizer_opcodes`` splits the same ops by their HLO opcode, so
+  that the ops XLA makes (copies, zero fills, casts), which carry no
+  name stack, can be read by what they are.
 - ``compile_window`` reads the program's ``CompileCounters`` over the
   traced window of ``ctx["spans"]`` (host ``perf_counter`` seconds).
 - ``anchor_offsets_ns`` and ``idle_in_compile_share`` put the counters'
@@ -34,7 +37,8 @@ except ImportError:      # a program older than its tracing
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s.*\{\s*$")
 _CALLS = re.compile(r"\bfusion\(.*\bcalls=%([\w.\-]+)")
-_OPCODE = re.compile(r"^\s*(?:ROOT\s+)?%([^\s=]+) = \S+ ([\w-]+)\(", re.M)
+_HEAD = re.compile(r"^\s*(?:ROOT\s+)?%([^\s=]+) = ")
+_OPCODE = re.compile(r"\s*([\w-]+)\(")
 
 
 def hlo_scopes(hlo: str) -> Dict[str, Optional[str]]:
@@ -62,6 +66,30 @@ def hlo_scopes(hlo: str) -> Dict[str, Optional[str]]:
     for name, stack in stacks.items():
         sc = telemetry.innermost_scope(stack)
         out[name] = sc or inner.get(calls.get(name))
+    return out
+
+
+def hlo_opcodes(hlo: str) -> Dict[str, str]:
+    """{instruction: opcode} of an optimized HLO module's text. The result
+    shape before the opcode may be a tuple, with spaces and parentheses of
+    its own (a multi-output fusion, ``copy-start``)."""
+    out = {}
+    for line in hlo.splitlines():
+        head = _HEAD.match(line)
+        if not head:
+            continue
+        rest, end = line[head.end():], 0
+        if rest.startswith("("):
+            depth = 0
+            for end, ch in enumerate(rest, 1):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+        else:
+            end = rest.find(" ")
+        op = _OPCODE.match(rest, max(end, 0))
+        if op:
+            out[head.group(1)] = op.group(1)
     return out
 
 
@@ -110,10 +138,26 @@ def optimizer_scopes(path: str, n_steps: int, n_devices: int,
     scope = hlo_scopes(hlo)
     if not any(scope.values()):
         return None
+    return _per_step(path, n_steps, n_devices, hlo,
+                     lambda name: scope.get(name) or "unscoped")
+
+
+def optimizer_opcodes(path: str, n_steps: int, n_devices: int,
+                      hlo: str) -> Dict[str, float]:
+    """Device seconds per step of the optimizer class's ops, by the HLO
+    opcode of the instruction each ran, averaged over the devices."""
+    opcode = hlo_opcodes(hlo)
+    return _per_step(path, n_steps, n_devices, hlo,
+                     lambda name: opcode.get(name, ""))
+
+
+def _per_step(path, n_steps, n_devices, hlo, group) -> Dict[str, float]:
+    """Device seconds per step of the optimizer class's ops, summed by
+    ``group(instruction)``, averaged over the devices."""
     events, nd = optimizer_events(path, n_devices, hlo)
     out: Dict[str, float] = {}
     for name, ns in events:
-        key = scope.get(name) or "unscoped"
+        key = group(name)
         out[key] = out.get(key, 0.0) + ns
     return {k: v / nd / n_steps * 1e-9 for k, v in sorted(out.items())}
 
@@ -124,7 +168,7 @@ def unscoped_ops(path: str, n_steps: int, n_devices: int,
     class's ops that no scope claims: ops XLA made (copies, zero fills,
     casts), which carry no JAX name stack."""
     scope = hlo_scopes(hlo)
-    opcode = dict(_OPCODE.findall(hlo))
+    opcode = hlo_opcodes(hlo)
     events, nd = optimizer_events(path, n_devices, hlo)
     out: Dict[str, float] = {}
     for name, ns in events:

@@ -95,10 +95,9 @@ def test_control_reads_far_above_the_program(workload):
 
 
 def test_window_compiles_as_the_cli_does():
-    """Set-up, window and reference persist every compile, so that the
-    input pipeline's per-step scan is always loaded from the cache (the
-    state a long ``repro.launch.train`` run reaches at its first compile
-    over JAX's one-second threshold), and the defaults come back after."""
+    """Set-up, window and reference persist every compile, so that a warm
+    run's set-up loads every program it needs from the cache however quick
+    its compile was, and the defaults come back after."""
     key = "jax_persistent_cache_min_compile_time_secs"
     default = getattr(jax.config, key)
     seen = []

@@ -5,6 +5,7 @@ import pathlib
 import pytest
 
 from benchmarks.chip import flops as F
+from benchmarks.chip.metrics import step_kind
 
 HERE = pathlib.Path(__file__).resolve().parent
 
@@ -45,17 +46,34 @@ def test_model_flops_per_token_by_hand(name, seq, flops):
     assert F.model_flops_per_token(_model(name), seq) == flops
 
 
-@pytest.mark.parametrize("kind,n,per_param", [
-    ("local", 1, 32), ("local+var", 1, 36), ("sync", 1, 52),
-    ("sync+var", 1, 56), ("sync", 4, 46), ("sync+var", 4, 50)])
+KINDS = [("local", 1, 32), ("local+var", 1, 36), ("sync", 1, 52),
+         ("sync+var", 1, 56), ("sync", 4, 46), ("sync+var", 4, 50)]
+
+
+@pytest.mark.parametrize("kind,n,per_param", KINDS)
 def test_optimizer_bytes_by_hand(kind, n, per_param):
     assert F.optimizer_bytes_per_param(kind, n) == per_param
-    assert F.optimizer_bytes(10, n, kind) == 10 * per_param
+    assert F.optimizer_bytes(_model("gpt2"), n, kind) == \
+        124_564_992 * per_param
+
+
+@pytest.mark.parametrize("name,params", [("bert-large", 334_268_416),
+                                         ("gpt2", 124_564_992)])
+@pytest.mark.parametrize("kind,n,per_param", KINDS + [("local", 4, 32),
+                                                      ("local+var", 4, 36)])
+def test_optimizer_bytes_of_a_model_is_its_params_at_the_dp_rate(
+        name, params, kind, n, per_param):
+    """Every leaf of the dense transformer takes the data-parallel path,
+    so a step moves the parameter count times 0/1 Adam's rate, the same
+    float as the count times the rate."""
+    got = F.optimizer_bytes(_model(name), n, kind)
+    assert got == params * F.optimizer_bytes_per_param(kind, n)
+    assert got == float(params * per_param)
 
 
 def test_step_kind_names():
-    assert F.step_kind(True, False) == "sync"
-    assert F.step_kind(False, False) == "local"
-    assert F.step_kind(True, True) == "sync+var"
+    assert step_kind(True, False) == "sync"
+    assert step_kind(False, False) == "local"
+    assert step_kind(True, True) == "sync+var"
     with pytest.raises(ValueError):
         F.optimizer_bytes_per_param("both", 1)

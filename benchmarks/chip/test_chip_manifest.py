@@ -68,6 +68,34 @@ def test_configs_resolve():
             assert NAME.match(k) and k in body and not WIDTH.search(k), k
 
 
+# what each configuration's modules provide (benchmarks/chip/__init__.py)
+MODULES = {"reference": ("init_params", "run", "compare"),
+           "counts": ("param_count", "model_flops_per_token",
+                      "optimizer_bytes")}
+
+
+@pytest.mark.parametrize("c", MAN["configs"], ids=lambda c: c["name"])
+def test_configs_name_their_modules(c):
+    body = json.loads((ROOT / c["file"]).read_text())
+    for key, funcs in MODULES.items():
+        assert NAME.match(body[key]) and (HERE / f"{body[key]}.py").is_file()
+        mod = importlib.import_module(f"benchmarks.chip.{body[key]}")
+        for f in funcs:
+            assert callable(getattr(mod, f, None)), (body[key], f)
+
+
+def test_only_their_tests_import_the_shared_modules():
+    """The harness and the readers reach a model only through the cell's
+    modules, so that a new configuration needs no edit of theirs."""
+    shared = re.compile(r"^\s*(from benchmarks\.chip import (reference|flops)"
+                        r"\b|import benchmarks\.chip\.(reference|flops))",
+                        re.M)
+    for p in sorted(HERE.rglob("*.py")):
+        if p.name.startswith("test_"):
+            continue
+        assert not shared.search(p.read_text()), p.relative_to(HERE)
+
+
 def test_workloads_resolve():
     wls = MAN["workloads"]
     assert 1 <= len(wls) <= 24

@@ -11,7 +11,8 @@ import pytest
 
 from benchmarks.chip import scopes as SC
 from benchmarks.chip import trace as TR
-from benchmarks.chip.metrics import compiles_per_step, host_compile_ms
+from benchmarks.chip.metrics import (compiles_per_step, encode_ms,
+                                     host_compile_ms, state_copy_ms)
 from benchmarks.chip.test_chip_trace import HLO, HOST, MODULES, OPS, _xspace
 from repro import telemetry
 
@@ -145,8 +146,8 @@ def test_anchor_puts_compiles_on_the_trace_clock(tmp_path):
 
 
 def test_compile_readers_count_the_window():
-    """A step that builds a new program every call, as
-    ``SyntheticLM.batch``'s scan does, reads one compile per step."""
+    """A step that builds a new program every call reads one compile per
+    step."""
     telemetry.CompileCounters.install()
     x = jnp.ones(4)
     jax.jit(lambda v: v + 1.0)(x).block_until_ready()      # before
@@ -187,4 +188,58 @@ def test_recorded_scoped_trace():
         sc["unscoped"], rel=1e-12)
     copies = sum(t for op, t in loose.values()
                  if op in ("copy", "copy-start", "copy-done"))
-    assert copies == pytest.approx(43.319e-3, rel=1e-4)
+    assert copies == pytest.approx(43.321e-3, rel=1e-4)
+
+
+def test_hlo_opcodes():
+    ops = SC.hlo_opcodes(SCOPED_HLO + """
+  %copy-start.2 = (f32[8]{0:T(8,128)}, f32[8]{0}, u32[]) copy-start(%p)
+  %fusion.9 = (f32[]{:T(128)}, f32[8]{0:T(8,128)S(1)}) fusion(%p), kind=kLoop
+""")
+    assert ops["fusion.1"] == "fusion" and ops["copy.8"] == "copy"
+    assert ops["all-to-all.9"] == "all-to-all"
+    assert ops["convert.1"] == "convert"
+    assert ops["copy-start.2"] == "copy-start"
+    assert ops["fusion.9"] == "fusion"
+
+
+def _ctx(path, hlo, n_steps):
+    return {"trace": TR.summarize(path, n_steps, 1, hlo),
+            "trace_path": path, "hlo": hlo, "kinds": ["sync"] * n_steps,
+            "chips": 1}
+
+
+def test_encode_and_copy_readers_on_a_hand_trace(tmp_path):
+    p = tmp_path / "scoped.pbtxt"
+    p.write_text(_xspace(SCOPED_OPS, MODULES, HOST))
+    ctx = _ctx(str(p), SCOPED_HLO, 1)
+    assert encode_ms.SCOPE in telemetry.SCOPES
+    assert encode_ms.read(ctx) == pytest.approx(2.0)
+    assert state_copy_ms.read(ctx) == pytest.approx(0.75)
+    assert SC.optimizer_opcodes(str(p), 1, 1, SCOPED_HLO) == pytest.approx(
+        {"fusion": 5.25e-3, "copy": 0.75e-3})
+    # a step program with no copy reads 0 ms of copies; one with no
+    # scopes reads no encode
+    no_copy = SCOPED_HLO.replace("%copy.8 = f32[8]{0} copy(%p)",
+                                 "%copy.8 = f32[8]{0} negate(%p)")
+    assert state_copy_ms.read(_ctx(str(p), no_copy, 1)) == 0.0
+    q = tmp_path / "hand.pbtxt"
+    q.write_text(_xspace(OPS, MODULES, HOST))
+    assert encode_ms.read(_ctx(str(q), HLO, 1)) is None
+
+
+def test_encode_and_copy_readers_on_the_recorded_traces():
+    """On the two recorded bert-large.sync1 steps: the encode's 24.35 ms,
+    and 45.02 ms of state copies outside the forward and backward, of
+    which 43.32 carry no scope (``test_recorded_scoped_trace``). The
+    older, unscoped program's trace has the same copies and no encode
+    reading."""
+    hlo = gzip.open(DATA / "bert-large.sync1.scoped.step_hlo.txt.gz",
+                    "rt").read()
+    ctx = _ctx(str(DATA / "bert-large.sync1.scoped.trace.pbtxt.gz"), hlo, 2)
+    assert encode_ms.read(ctx) == pytest.approx(24.349, rel=1e-4)
+    assert state_copy_ms.read(ctx) == pytest.approx(45.024, rel=1e-4)
+    hlo = gzip.open(DATA / "bert-large.sync1.step_hlo.txt.gz", "rt").read()
+    ctx = _ctx(str(DATA / "bert-large.sync1.trace.pbtxt.gz"), hlo, 2)
+    assert encode_ms.read(ctx) is None
+    assert state_copy_ms.read(ctx) == pytest.approx(45.014, rel=1e-4)
